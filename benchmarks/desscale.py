@@ -36,6 +36,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro import use_compilation_cache
 from repro.core import desgraph, desreplay
 from repro.core import simulator as sim
 from repro.core.group import DESLoopBackend, Group, single_group
@@ -168,6 +169,7 @@ def main() -> int:
                     help="CI gate: N=64 bit-identity + N=256 >= 5x")
     ap.add_argument("--json", type=Path, default=BENCH_PATH)
     args = ap.parse_args()
+    use_compilation_cache()
     if args.smoke:
         return smoke_gate()
     points = [bench_point(s) for s in SCALES]

@@ -33,11 +33,6 @@ are simulated-time):
 * ``fused_saturation`` — the fused program scaled over replicas x slots
   at fixed per-slot work until wall-clock throughput saturates; the
   curve is the capacity story of the device-resident serve plane.
-* ``compile_cache``  — cold-start with and without the JAX persistent
-  compilation cache (``REPRO_COMPILATION_CACHE``): three fresh
-  subprocesses (cache off / cache populate / cache warm) each timing the
-  same cold fused serve run; the delta is what a restarted serving
-  process saves when the executable deserializes instead of recompiling.
 * ``view_change``   — warm reconfigure-under-traffic: the
   virtual-synchrony cut of a live stream (wedge + ragged trim + epoch
   carry + new-stream hand-off, DESIGN.md Sec. 7) with the padded stack
@@ -69,6 +64,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro import use_compilation_cache
 from repro.core.group import Group, single_group
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -409,56 +405,6 @@ def bench_fused_saturation(ladder=SATURATION_LADDER):
     }
 
 
-def bench_compile_cache(shape):
-    """Cold-start delta from the JAX persistent compilation cache: three
-    fresh subprocesses time the SAME cold fused serve run — cache off,
-    cache populate (cold disk), cache warm (deserialize instead of
-    recompile).  The probe is this script's own ``--cold-probe`` mode so
-    the child measures exactly one process-cold fused run."""
-    import os
-    import subprocess
-    import tempfile
-
-    def probe(extra_env):
-        env = {**os.environ, "PYTHONPATH": str(ROOT / "src")}
-        env.pop("REPRO_COMPILATION_CACHE", None)
-        env.update(extra_env)
-        out = subprocess.run(
-            [sys.executable, str(Path(__file__).resolve()),
-             "--cold-probe"],
-            env=env, capture_output=True, text=True, check=True)
-        return json.loads(out.stdout.strip().splitlines()[-1])
-
-    with tempfile.TemporaryDirectory() as cache_dir:
-        off = probe({})
-        populate = probe({"REPRO_COMPILATION_CACHE": cache_dir})
-        warm = probe({"REPRO_COMPILATION_CACHE": cache_dir})
-    return {
-        "cold_run_s_no_cache": off["cold_run_s"],
-        "cold_run_s_cache_populate": populate["cold_run_s"],
-        "cold_run_s_cache_warm": warm["cold_run_s"],
-        "cold_start_delta_s": round(
-            off["cold_run_s"] - warm["cold_run_s"], 4),
-        "speedup_cold_start": round(
-            off["cold_run_s"] / max(warm["cold_run_s"], 1e-9), 2),
-    }
-
-
-def cold_probe(shape) -> dict:
-    """Child-process body of ``bench_compile_cache``: one process-cold
-    fused serve run, wall-clocked from engine build to report."""
-    from repro.serve.fanout import ReplicatedEngine
-
-    engines, cfg = _serve_engines(shape)
-    rep = ReplicatedEngine(engines, subscribers_per_replica=2, window=4)
-    _fill_serve(rep, shape, cfg)
-    t0 = time.perf_counter()
-    report = rep.run(fused=True)
-    dt = time.perf_counter() - t0
-    return {"cold_run_s": round(dt, 4),
-            "fused": bool(report.extras["serve"]["fused"])}
-
-
 def bench_view_change(shape, backend="graph"):
     """Warm reconfigure-under-traffic: the virtual-synchrony cut of a
     LIVE stream (wedge at the SST watermarks, ragged trim, epoch carry,
@@ -660,12 +606,8 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes; fail on >3x regression vs baseline")
     ap.add_argument("--json", type=Path, default=BENCH_PATH)
-    ap.add_argument("--cold-probe", action="store_true",
-                    help=argparse.SUPPRESS)   # bench_compile_cache child
     args = ap.parse_args()
-    if args.cold_probe:
-        print(json.dumps(cold_probe(SMOKE_SERVE)))
-        return 0
+    use_compilation_cache()
     if args.smoke:
         return smoke_gate(args.json)
     record = {
@@ -689,7 +631,6 @@ def main() -> int:
     }
     full = record["full"]
     record["fused_saturation"] = bench_fused_saturation()
-    record["compile_cache"] = bench_compile_cache(SMOKE_SERVE)
     full["vs_pre_pr"] = {
         "graph_second_run_speedup": round(
             PRE_PR["graph_second_run_s"]
@@ -726,7 +667,6 @@ def main() -> int:
           and all(p["fused"] for p in sat["curve"])
           and sat["peak_tok_per_s"] >= full["serve_fused"][
               "tok_per_s_warm"]
-          and record["compile_cache"]["cold_start_delta_s"] > 0
           and full["view_change"]["reused_program"]
           and full["view_change"]["resend_msgs"] > 0
           and full["slot_failure"]["reused_program"]

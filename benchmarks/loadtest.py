@@ -50,6 +50,7 @@ import sys
 import time
 from pathlib import Path
 
+from repro import use_compilation_cache
 from repro.api import Group, single_group, trace_snapshot
 from repro.load import (Poisson, Profile, Stage, WindowSlack, run_profile,
                         staged_ramp)
@@ -361,6 +362,7 @@ def main() -> int:
                     help="3-point sweep; fail on regression vs baseline")
     ap.add_argument("--json", type=Path, default=BENCH_PATH)
     args = ap.parse_args()
+    use_compilation_cache()
     if args.smoke:
         return smoke_gate(args.json)
     record = {

@@ -18,6 +18,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro import use_compilation_cache
 from repro.core import costmodel, dds, simulator as sim
 from repro.core.group import Group, RunReport, single_group
 
@@ -368,6 +369,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None)
     args = ap.parse_args()
+    use_compilation_cache()
     names = args.only if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     t0 = time.time()

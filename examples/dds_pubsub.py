@@ -7,6 +7,7 @@ topic at each QoS level — the paper's Fig. 18 scenario.
 Run:  PYTHONPATH=src python examples/dds_pubsub.py
 """
 
+from repro import use_compilation_cache
 from repro.core import dds
 from repro.core.group import RunReport
 
@@ -19,6 +20,7 @@ def bench(qos: dds.QoS, spindle: bool, samples: int = 400) -> RunReport:
 
 
 def main():
+    use_compilation_cache()
     print("DDS domain: 1 publisher, 15 subscribers, 10KB samples")
     print(f"{'QoS':<18} {'baseline GB/s':>14} {'spindle GB/s':>14} "
           f"{'speedup':>8}")

@@ -7,10 +7,12 @@ with null-rounds, admits a replacement, and never stalls.
 Run:  PYTHONPATH=src python examples/elastic_failover.py
 """
 
+from repro import use_compilation_cache
 from repro.train.elastic import ElasticConfig, ElasticRuntime
 
 
 def main():
+    use_compilation_cache()
     rt = ElasticRuntime(members=list(range(16)),
                         cfg=ElasticConfig(heartbeat_timeout=3,
                                           checkpoint_every=10))

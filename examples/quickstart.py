@@ -15,7 +15,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax
 import numpy as np
 
-from repro import api
+from repro import api, use_compilation_cache
 from repro.core import gradsync
 
 
@@ -79,6 +79,7 @@ def gradsync_demo():
 
 
 if __name__ == "__main__":
+    use_compilation_cache()
     protocol_demo()
     nullsend_demo()
     backend_demo()

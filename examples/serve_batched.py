@@ -21,6 +21,7 @@ Run:  PYTHONPATH=src python examples/serve_batched.py
 import jax
 import numpy as np
 
+from repro import use_compilation_cache
 from repro.models import layers, registry
 from repro.models.runtime import Runtime
 from repro.serve.engine import EngineConfig, Request, ServeEngine
@@ -28,6 +29,7 @@ from repro.serve.fanout import ReplicatedEngine
 
 
 def main():
+    use_compilation_cache()
     arch = registry.get("qwen3-1.7b")
     cfg = arch.cfg.reduced()
     params = layers.init_tree(registry.param_specs(cfg), jax.random.key(0))

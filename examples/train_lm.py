@@ -10,6 +10,7 @@ import argparse
 import dataclasses
 import tempfile
 
+from repro import use_compilation_cache
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.models.runtime import Runtime
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    use_compilation_cache()
 
     cfg = model_tiny() if args.tiny else model_100m()
     registry.register(cfg.name, lambda c=cfg: c)

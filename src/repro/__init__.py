@@ -1,65 +1,29 @@
 """repro — Spindle (RDMA atomic multicast optimizations) as a multi-pod
 JAX training/serving framework.  See README.md and DESIGN.md."""
 
+import os
 
-def enable_compilation_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path``.
+# The checkout's own persistent compile cache.  A later process finds the
+# entries only at the same path, so it is fixed: never a temp name, pid or
+# time.
+CHECKOUT_COMPILATION_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    Every compile-once program in the repo (the stacked scan/stream
-    programs, the fused serve program, jitted decode steps) is re-traced
-    per PROCESS; across processes the trace is cheap but the XLA compile
-    is not.  With the cache on, a cold process deserializes previously
-    compiled executables from disk instead of recompiling — the
-    cold-start delta is measured by ``benchmarks/hotpath.py``
-    (``compile_cache`` row in BENCH_hotpath.json).
 
-    The directory is created if missing (XLA's cache writer does not
-    mkdir for you; a nonexistent dir silently caches nothing).
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point (the
+    chip smoke, a benchmark, an example) and return its directory.
 
-    Zero thresholds so even the sub-second CPU compiles of the test
-    shapes are cached — the default thresholds only persist compiles
-    over a second, which on the benchmark shapes would cache nothing.
-    """
-    import os
-
+    One rule: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and nothing is set here; otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` (git-ignored).  Library code and the
+    tests never call this, so a test run leaves the checkout clean."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     import jax
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
-
-def _enable_cache_from_env() -> None:
-    """Opt-in via environment so every entry point (pytest, benchmarks,
-    subprocesses) inherits the cache without code changes:
-    ``REPRO_COMPILATION_CACHE`` names the cache directory; unset/empty
-    leaves JAX's default (off).
-
-    The env var is read ONCE, at ``import repro`` — setting it after
-    this module (or jax's cache config) is already loaded cannot take
-    effect, and an explicit ``jax_compilation_cache_dir`` someone
-    already configured wins over the env var.  Both used to be silent;
-    now the losing env var warns once so a "why is nothing cached?" hunt
-    ends here instead of in XLA."""
-    import os
-
-    cache_dir = os.environ.get("REPRO_COMPILATION_CACHE")
-    if not cache_dir:
-        return
-    import jax
-
-    configured = jax.config.jax_compilation_cache_dir
-    if configured and configured != cache_dir:
-        import warnings
-
-        warnings.warn(
-            "REPRO_COMPILATION_CACHE=%r ignored: jax was already "
-            "configured with jax_compilation_cache_dir=%r (explicit "
-            "configuration wins; unset one of them)"
-            % (cache_dir, configured), RuntimeWarning, stacklevel=2)
-        return
-    enable_compilation_cache(cache_dir)
-
-
-_enable_cache_from_env()
+    jax.config.update("jax_compilation_cache_dir",
+                      CHECKOUT_COMPILATION_CACHE)
+    return CHECKOUT_COMPILATION_CACHE

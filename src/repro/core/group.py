@@ -1069,22 +1069,28 @@ class GraphBackend:
                           for spec in cfg.subgroups]).astype(np.float32)
         return members, senders, windows, rounds, scheds, costs
 
+    def program(self, cfg: GroupConfig, counts: Dict[int, np.ndarray]):
+        """The stacked scan program :meth:`run` dispatches for one
+        scenario, its device inputs and the per-subgroup round budgets
+        (``program.lower(*args)`` shows what the device runs)."""
+        members, senders, windows, rounds, scheds, costs = \
+            self._stack(cfg, counts)
+        member_masks, sender_masks = _stack_masks(members, senders)
+        masked = member_masks is not None
+        program = _scan_program(len(members), max(members), max(senders),
+                                windows, masked, cfg.flags.null_send,
+                                self.name)
+        args = [jnp.asarray(scheds), jnp.asarray(costs)]
+        if masked:
+            args += [jnp.asarray(member_masks), jnp.asarray(sender_masks)]
+        return program, args, rounds
+
     def run(self, cfg: GroupConfig, counts: Dict[int, np.ndarray]
             ) -> Tuple[RunReport, Dict[int, DeliveryLog]]:
         agg = _GraphAgg()
         wall0 = time.perf_counter()
         if cfg.subgroups:
-            members, senders, windows, rounds, scheds, costs = \
-                self._stack(cfg, counts)
-            member_masks, sender_masks = _stack_masks(members, senders)
-            masked = member_masks is not None
-            program = _scan_program(len(members), max(members),
-                                    max(senders), windows, masked,
-                                    cfg.flags.null_send, self.name)
-            args = [jnp.asarray(scheds), jnp.asarray(costs)]
-            if masked:
-                args += [jnp.asarray(member_masks),
-                         jnp.asarray(sender_masks)]
+            program, args, rounds = self.program(cfg, counts)
             outs = [np.asarray(o) for o in program(*args)]
             self._finalize(cfg, counts, outs, rounds, agg)
         return self._report(agg, wall0), agg.logs
@@ -1137,9 +1143,11 @@ class GraphBackend:
         n_shards = placement_mod.shard_count(b)
         program = _batch_program(members, senders, ring, self.name,
                                  n_shards)
-        outs = [np.asarray(o) for o in program(
-            jnp.asarray(scheds), jnp.asarray(windows),
-            jnp.asarray(nulls_on), jnp.asarray(costs))]
+        raw = program(jnp.asarray(scheds), jnp.asarray(windows),
+                      jnp.asarray(nulls_on), jnp.asarray(costs))
+        # where the grid actually ran: the devices holding its output
+        n_devices = len(raw[0].sharding.device_set)
+        outs = [np.asarray(o) for o in raw]
         results = []
         for i in range(b):
             agg = _GraphAgg()
@@ -1147,9 +1155,9 @@ class GraphBackend:
                            [o[i] for o in outs], stacks[i][3], agg)
             # one wall clock covers the whole grid — stamp it under a
             # batch key so nobody mistakes it for a per-point cost
-            results.append((self._report(agg, wall0,
-                                         wall_key="batch_wall_s"),
-                            agg.logs))
+            report = self._report(agg, wall0, wall_key="batch_wall_s")
+            report.extras["batch_devices"] = n_devices
+            results.append((report, agg.logs))
         return results
 
     # -- host-side post-processing -------------------------------------------

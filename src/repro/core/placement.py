@@ -16,11 +16,11 @@ graceful degradation on a CPU-only host.  The mesh reuses
 construction (and its ``data`` axis name) when every device participates,
 trimming to a prefix of ``jax.devices()`` otherwise.
 
-The shard_map wrapper passes ``check_rep=False``: shard_map's replication
-analysis has no rule for ``pallas_call``, so the pallas backend's sharded
-grid would crash with the check on — and nothing here relies on
-replication tracking (every output is sharded exactly like the inputs;
-there is no cross-shard communication to analyze).
+The shard_map wrapper passes ``check_vma=False``: shard_map's
+varying-manual-axes analysis has no rule for ``pallas_call`` and rejects
+the scan carry of the pallas backend's sharded grid — and nothing here
+relies on it (every output is sharded exactly like the inputs; there is
+no cross-shard communication to analyze).
 """
 
 from __future__ import annotations
@@ -29,6 +29,7 @@ from typing import Callable
 
 import jax
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.launch import mesh as mesh_mod
 
@@ -67,22 +68,8 @@ def shard_over_batch(fn: Callable, n_shards: int,
     points are independent there is no cross-shard communication — the
     sharded program is the vmapped program, n_shards times narrower.
     """
-    try:
-        from jax import shard_map            # jax >= 0.5 spelling
-    except ImportError:                      # this container's 0.4.x
-        from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     mesh = batch_mesh(n_shards)
-    axis = mesh.axis_names[0]
-    spec = P(axis)
-    in_specs = tuple(spec for _ in range(n_batched_args))
-    try:
-        # check_rep's replication analysis has no rule for pallas_call,
-        # so the pallas backend's sharded grid would crash with it on —
-        # and nothing here relies on replication tracking (every output
-        # is sharded like the inputs).
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
-                         check_rep=False)
-    except TypeError:                        # kwarg renamed in newer jax
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec)
+    spec = P(mesh.axis_names[0])
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(spec,) * n_batched_args, out_specs=spec,
+                         check_vma=False)

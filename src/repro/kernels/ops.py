@@ -101,16 +101,13 @@ def rms_norm_residual(x, residual, weight, eps: float = 1e-6, *,
     return o[:t].reshape(shape), r[:t].reshape(shape)
 
 
-def smc_sweep(counters, processed, *, block_senders: int = 8):
+def smc_sweep(counters, processed):
     """Batched receive-predicate sweep (see kernels.smc_sweep).  The kernel
-    pads non-multiple sender counts internally."""
-    return _ss.smc_sweep_pallas(counters, processed,
-                                block_senders=block_senders,
-                                interpret=_interpret())
+    pads the sender axis to whole tiles internally."""
+    return _ss.smc_sweep_pallas(counters, processed, interpret=_interpret())
 
 
-def smc_sweep_watermark(published, processed, *, window: int, valid=None,
-                        block_senders: int = 8):
+def smc_sweep_watermark(published, processed, *, window: int, valid=None):
     """Receive sweep from published watermarks only — the counter ring is
     rebuilt inside the kernel tile, so no (S, W) array is materialized
     (see kernels.smc_sweep).  The Group ``pallas`` backend's per-round
@@ -118,5 +115,4 @@ def smc_sweep_watermark(published, processed, *, window: int, valid=None,
     stacked multi-subgroup execution."""
     return _ss.smc_sweep_watermark_pallas(published, processed,
                                           window=window, valid=valid,
-                                          block_senders=block_senders,
                                           interpret=_interpret())

@@ -5,7 +5,7 @@ Given every sender's slot-counter ring (S, W) and the per-sender processed
 counts, compute in ONE pass (a) the new visible count per sender (the
 contiguous-slot scan of the receive predicate) and (b) the round-robin
 received_num prefix — i.e. a whole receive-predicate iteration for all
-senders, fused.  The polling area streams HBM->VMEM in (senders x window)
+senders, fused.  The polling area streams HBM->VMEM in (window x lanes)
 tiles; this is the structural analogue of keeping the SMC polling area
 cache-resident (Fig. 6's w=100 sweet spot).
 
@@ -19,9 +19,11 @@ Two entry points:
   materialized in HBM.  This is the Group hot path: per protocol round it
   moves O(S) instead of O(S*W) bytes.
 
-Both pad the sender axis to a ``block_senders`` multiple internally (any
-sender count runs; results are sliced back) and compile to Mosaic on TPU,
-falling back to interpret mode elsewhere (``interpret=None`` = auto).
+Layout: the sender (lane) axis is padded to whole int32 ``(8, 128)`` vreg
+tiles and viewed as ``(rows, 128)``; each grid step sweeps one tile (the
+explicit ring as a ``(W, 8, 128)`` block, slot-major).  Any sender count
+runs and results are sliced back.  The kernels compile to Mosaic on TPU
+and fall back to interpret mode elsewhere (``interpret=None`` = auto).
 """
 
 from __future__ import annotations
@@ -31,7 +33,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+_ROWS, _LANES = 8, 128            # one int32 vreg tile
+_TILE = _ROWS * _LANES
 
 
 def _auto_interpret(interpret) -> bool:
@@ -41,43 +45,45 @@ def _auto_interpret(interpret) -> bool:
     return interpret
 
 
-def _contiguous_run(counters, processed, window: int):
+def _contiguous_run(counter_at, processed, window: int):
     """Shared predicate core: length of the contiguous visible run starting
-    at ``processed`` given a (bs, W) counter tile."""
-    bs = counters.shape[0]
-    # candidate message indexes k = processed + j, j in [0, W)
-    j = jax.lax.broadcasted_iota(jnp.int32, (bs, window), 1)
-    ks = processed[:, None] + j
-    slots = ks % window
-    want = ks // window
-    have = jnp.take_along_axis(counters, slots, axis=1) >= want
-    return jnp.cumprod(have.astype(jnp.int32), axis=1).sum(axis=1)
+    at ``processed``, i.e. the first candidate ``j`` in ``[0, W)`` whose
+    message ``k = processed + j`` is missing from slot ``k % W`` (``W`` if
+    none is).  ``counter_at(slot)`` returns that slot's counter for every
+    lane of the tile.  The loop walks the ring slot by slot, and each slot
+    holds exactly one candidate, so no lane ever gathers."""
+
+    def body(slot, run):
+        j = (slot - processed) % window           # the candidate in `slot`
+        have = counter_at(slot) >= (processed + j) // window
+        return jnp.minimum(run, jnp.where(have, window, j))
+
+    return jax.lax.fori_loop(0, window, body,
+                             jnp.full(processed.shape, window, jnp.int32))
 
 
 def _sweep_kernel(counters_ref, processed_ref, visible_ref, *, window: int):
-    counters = counters_ref[...]                  # (bs, W) int32
-    processed = processed_ref[...]                # (bs,)  int32
-    visible_ref[...] = processed + _contiguous_run(counters, processed,
-                                                   window)
+    processed = processed_ref[...]                # (8, 128) int32
+    visible_ref[...] = processed + _contiguous_run(
+        lambda slot: counters_ref[slot], processed, window)
 
 
 def _watermark_run(published, processed, window: int):
-    """Shared tile core of the watermark kernels: rebuild the counter tile
+    """Shared tile core of the watermark kernels: rebuild each counter slot
     in-registers from the published watermark (see
     :func:`counters_from_counts` for the ring state being reproduced — no
     (S, W) array crosses HBM) and return the contiguous visible run."""
-    bs = published.shape[0]
-    slots = jax.lax.broadcasted_iota(jnp.int32, (bs, window), 1)
-    pub = published[:, None]
-    counters = jnp.where(pub > slots, (pub - 1 - slots) // window, -1)
-    return _contiguous_run(counters, processed, window)
+    return _contiguous_run(
+        lambda slot: jnp.where(published > slot,
+                               (published - 1 - slot) // window, -1),
+        processed, window)
 
 
 def _watermark_kernel(published_ref, processed_ref, visible_ref, *,
                       window: int):
     """Receive sweep from published watermarks, ring rebuilt in-kernel."""
-    published = published_ref[...]                # (bs,) int32
-    processed = processed_ref[...]                # (bs,) int32
+    published = published_ref[...]                # (8, 128) int32
+    processed = processed_ref[...]                # (8, 128) int32
     visible_ref[...] = processed + _watermark_run(published, processed,
                                                   window)
 
@@ -90,9 +96,9 @@ def _watermark_masked_kernel(published_ref, processed_ref, valid_ref,
     ranks arrive here as lanes whose ring must stay untouched.  An invalid
     lane returns ``processed`` unchanged (no advancement), whatever its
     published watermark holds."""
-    published = published_ref[...]                # (bs,) int32
-    processed = processed_ref[...]                # (bs,) int32
-    valid = valid_ref[...]                        # (bs,) int32 (0/1)
+    published = published_ref[...]                # (8, 128) int32
+    processed = processed_ref[...]                # (8, 128) int32
+    valid = valid_ref[...]                        # (8, 128) int32 (0/1)
     run = _watermark_run(published, processed, window)
     visible_ref[...] = processed + jnp.where(valid > 0, run, 0)
 
@@ -114,45 +120,42 @@ def counters_from_counts(published, window: int):
     return jnp.where(pub > slots, (pub - 1 - slots) // window, -1)
 
 
-def _pad_senders(arrays, block_senders: int, pad_values):
-    s = arrays[0].shape[0]
-    pad = (-s) % block_senders
-    if pad:
-        arrays = [jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1),
-                          constant_values=v)
-                  for a, v in zip(arrays, pad_values)]
-    return arrays, s, s + pad
+def _to_tiles(x, fill: int):
+    """(..., S) -> (..., rows, 128) int32: the lane axis padded with
+    ``fill`` to whole (8, 128) tiles (padding lanes sweep an empty ring)."""
+    x = jnp.asarray(x, jnp.int32)
+    pad = (-x.shape[-1]) % _TILE
+    x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),),
+                constant_values=fill)
+    return x.reshape(x.shape[:-1] + (-1, _LANES))
 
 
-def smc_sweep_pallas(counters, processed, *, block_senders: int = 8,
-                     interpret=None):
+_LANE_SPEC = pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))
+
+
+def smc_sweep_pallas(counters, processed, *, interpret=None):
     """counters: (S, W) int32 slot counters; processed: (S,) int32.
     Returns visible counts (S,) — the batched receive for every sender.
 
-    Any S runs: the sender axis is padded to a ``block_senders`` multiple
-    (padding rows sweep an empty ring) and the result sliced back.
+    Any S runs: the sender axis is padded to whole (8, 128) tiles (padding
+    lanes sweep an empty ring) and the result sliced back.
     """
-    w = counters.shape[1]
-    (counters, processed), s, sp = _pad_senders(
-        [counters.astype(jnp.int32), processed.astype(jnp.int32)],
-        block_senders, pad_values=(-1, 0))
+    s, w = counters.shape
+    ring = _to_tiles(jnp.asarray(counters).T, fill=-1)     # (W, rows, 128)
     out = pl.pallas_call(
         functools.partial(_sweep_kernel, window=w),
-        grid=(sp // block_senders,),
-        in_specs=[
-            pl.BlockSpec((block_senders, w), lambda i: (i, 0)),
-            pl.BlockSpec((block_senders,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block_senders,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((sp,), jnp.int32),
+        grid=(ring.shape[1] // _ROWS,),
+        in_specs=[pl.BlockSpec((w, _ROWS, _LANES), lambda i: (0, i, 0)),
+                  _LANE_SPEC],
+        out_specs=_LANE_SPEC,
+        out_shape=jax.ShapeDtypeStruct(ring.shape[1:], jnp.int32),
         interpret=_auto_interpret(interpret),
-    )(counters, processed)
-    return out[:s]
+    )(ring, _to_tiles(processed, fill=0))
+    return out.reshape(-1)[:s]
 
 
 def smc_sweep_watermark_pallas(published, processed, *, window: int,
-                               valid=None, block_senders: int = 8,
-                               interpret=None):
+                               valid=None, interpret=None):
     """published/processed: (S,) int32 -> visible counts (S,).
 
     Same fixed point as :func:`smc_sweep_pallas` over
@@ -164,24 +167,23 @@ def smc_sweep_watermark_pallas(published, processed, *, window: int,
     padded execution.  The lane axis here is really a flattened
     (member, sender) plane when driven by the Group backends, so the mask
     covers member-axis padding as well as sender-axis padding: an invalid
-    lane's result is its ``processed`` count unchanged.  (The internal
-    block padding below is the third, kernel-private padding level.)
+    lane's result is its ``processed`` count unchanged.  (The tile padding
+    below is the third, kernel-private padding level.)
     """
-    operands = [jnp.asarray(published, jnp.int32),
-                jnp.asarray(processed, jnp.int32)]
+    s = published.shape[0]
+    operands = [published, processed]
     kernel = _watermark_kernel
     if valid is not None:
-        operands.append(jnp.asarray(valid, jnp.int32))
+        operands.append(valid)
         kernel = _watermark_masked_kernel
-    operands, s, sp = _pad_senders(operands, block_senders,
-                                   pad_values=(0,) * len(operands))
-    lane_spec = pl.BlockSpec((block_senders,), lambda i: (i,))
+    operands = [_to_tiles(x, fill=0) for x in operands]
+    rows = operands[0].shape[0]
     out = pl.pallas_call(
         functools.partial(kernel, window=window),
-        grid=(sp // block_senders,),
-        in_specs=[lane_spec] * len(operands),
-        out_specs=lane_spec,
-        out_shape=jax.ShapeDtypeStruct((sp,), jnp.int32),
+        grid=(rows // _ROWS,),
+        in_specs=[_LANE_SPEC] * len(operands),
+        out_specs=_LANE_SPEC,
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
         interpret=_auto_interpret(interpret),
     )(*operands)
-    return out[:s]
+    return out.reshape(-1)[:s]

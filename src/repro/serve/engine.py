@@ -43,6 +43,15 @@ from repro.models import layers, masking, registry
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.models.runtime import Runtime
 
+# Compile options of every program that runs the decode body.  XLA on a
+# TPU otherwise keeps bf16 intermediates in f32 inside a fusion and
+# rounds only at its edge, so the same body compiled into two programs
+# (the per-round step here, the fused serve loop) rounds at different
+# points and near-tied logits argmax differently.  Rounding every op as
+# written makes the result independent of fusion: the two paths agree
+# bit for bit on the chip as on the CPU.
+DECODE_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
 
 @dataclasses.dataclass
 class Request:
@@ -117,7 +126,8 @@ class ServeEngine:
 
         self._decode_body = _decode_body
         self._reset_body = _reset_body
-        self.decode = jax.jit(_decode_body, donate_argnums=(1,))
+        self.decode = jax.jit(_decode_body, donate_argnums=(1,),
+                              compiler_options=DECODE_COMPILER_OPTIONS)
         self._reset_slots = jax.jit(_reset_body, donate_argnums=(0,))
         # slot state (the SMC ring of the serving plane)
         self.slot_req: List[Optional[Request]] = [None] * b

@@ -75,6 +75,7 @@ from repro.core import sweep as sweep_mod
 from repro.core.group import TRACE_EVENTS, RunReport, fused_stream_program
 from repro.models import masking
 from repro.models.layers import ParamSpec
+from repro.serve.engine import DECODE_COMPILER_OPTIONS
 
 
 class FusedUnsupported(Exception):
@@ -539,7 +540,7 @@ def _build_program(decode_body, reset_body, specs, shapes, rank_slot):
             _unfold_caches(specs, out["caches"], n_g, B))
         return out
 
-    return jax.jit(program)
+    return jax.jit(program, compiler_options=DECODE_COMPILER_OPTIONS)
 
 
 def _owner_fill(tb_admit: np.ndarray, init_rid: np.ndarray) -> np.ndarray:

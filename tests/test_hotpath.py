@@ -231,10 +231,12 @@ def test_run_batch_rejects_mismatched_grid_lengths():
 
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache: env opt-in (repro.__init__)
+# persistent compilation cache: one rule (repro.use_compilation_cache)
 # ---------------------------------------------------------------------------
 
 def _run_py(code, env_extra):
+    """Run ``code`` in a fresh interpreter: the rule configures jax
+    globally, which must not leak into this test process."""
     import os
     import pathlib
     import subprocess
@@ -244,7 +246,7 @@ def _run_py(code, env_extra):
 
     src = str(pathlib.Path(repro.__file__).resolve().parents[1])
     env = dict(os.environ)
-    env.pop("REPRO_COMPILATION_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["PYTHONPATH"] = src
     env.update(env_extra)
     out = subprocess.run([sys.executable, "-c", code], env=env,
@@ -253,27 +255,38 @@ def _run_py(code, env_extra):
     return out
 
 
-def test_compilation_cache_env_creates_missing_dir(tmp_path):
+def test_compilation_cache_honours_jax_env(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is the cache,
+    entries land there, and nothing in the checkout is configured."""
     cache = str(tmp_path / "cc" / "nested")
     _run_py(
-        "import os, jax, repro\n"
-        f"assert os.path.isdir({cache!r}), 'cache dir not created'\n"
-        f"assert jax.config.jax_compilation_cache_dir == {cache!r}\n",
-        {"REPRO_COMPILATION_CACHE": cache})
+        "import os, jax, jax.numpy as jnp, repro\n"
+        "assert jax.config.jax_compilation_cache_dir is not None\n"
+        f"assert repro.use_compilation_cache() == {cache!r}\n"
+        f"assert jax.config.jax_compilation_cache_dir == {cache!r}\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+        " 0.0)\n"
+        "jax.jit(lambda x: x * 3 + 1)(jnp.ones(4)).block_until_ready()\n"
+        f"assert os.listdir({cache!r}), 'nothing cached'\n",
+        {"JAX_COMPILATION_CACHE_DIR": cache})
 
 
-def test_compilation_cache_env_warns_when_jax_already_configured(
-        tmp_path):
-    mine = str(tmp_path / "mine")
-    theirs = str(tmp_path / "theirs")
-    _run_py(
-        "import warnings, jax\n"
-        f"jax.config.update('jax_compilation_cache_dir', {theirs!r})\n"
-        "with warnings.catch_warnings(record=True) as w:\n"
-        "    warnings.simplefilter('always')\n"
-        "    import repro\n"
-        "assert any('REPRO_COMPILATION_CACHE' in str(x.message)\n"
-        "           for x in w), [str(x.message) for x in w]\n"
-        "# explicit configuration wins; the env var must not clobber it\n"
-        f"assert jax.config.jax_compilation_cache_dir == {theirs!r}\n",
-        {"REPRO_COMPILATION_CACHE": mine})
+def test_compilation_cache_defaults_to_fixed_checkout_path():
+    """Without the env var the cache is ``<checkout>/.jax_cache``: the
+    same path in every process, ignored by git, and not switched on by
+    merely importing the package."""
+    import pathlib
+
+    import repro
+
+    root = pathlib.Path(repro.__file__).resolve().parents[2]
+    want = str(root / ".jax_cache")
+    assert repro.CHECKOUT_COMPILATION_CACHE == want
+    probe = (
+        "import jax, repro\n"
+        "assert jax.config.jax_compilation_cache_dir is None\n"
+        "print(repro.use_compilation_cache())\n"
+        f"assert jax.config.jax_compilation_cache_dir == {want!r}\n")
+    runs = {_run_py(probe, {}).stdout.strip() for _ in range(2)}
+    assert runs == {want}
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()

@@ -201,8 +201,8 @@ def test_smc_sweep_matches_ref(s, w):
 
 @pytest.mark.parametrize("s", [3, 5, 7, 9])
 def test_smc_sweep_pallas_pads_nondivisible_senders(s):
-    """The kernel itself (not just the ops wrapper) pads the sender axis:
-    3- or 5-sender subgroups run instead of tripping the old assert."""
+    """The kernel itself (not just the ops wrapper) pads the sender axis
+    to whole (8, 128) tiles: 3- or 5-sender subgroups run."""
     from repro.kernels import smc_sweep as ss
     rng = np.random.default_rng(11)
     w = 16
@@ -210,7 +210,7 @@ def test_smc_sweep_pallas_pads_nondivisible_senders(s):
     published = processed + rng.integers(0, w + 1, size=s)
     counters = np.asarray(ss.counters_from_counts(published, w))
     got = ss.smc_sweep_pallas(jnp.asarray(counters), jnp.asarray(processed),
-                              block_senders=8, interpret=True)
+                              interpret=True)
     np.testing.assert_array_equal(np.asarray(got), published)
 
 

@@ -236,6 +236,8 @@ _SHARDED_CONFORMANCE = textwrap.dedent("""
         reports = api.Group(make_cfg()).run_batch(backend=backend,
                                                   windows=windows)
         for w, rb in zip(windows, reports):
+            # the grid's output lives on (was computed by) all 4 devices
+            assert rb.extras["batch_devices"] == 4, (backend, w)
             base = make_cfg()
             subs = tuple(dataclasses.replace(s, window=w)
                          for s in base.subgroups)
@@ -270,7 +272,7 @@ _SHARDED_CONFORMANCE = textwrap.dedent("""
 def test_run_batch_shards_over_virtual_devices_bit_identically():
     """The multi-device path: grid points shard_mapped over 4 virtual
     CPU devices must be bit-identical to sequential single-device runs —
-    on graph AND pallas (the kernel path needs check_rep off in
+    on graph AND pallas (the kernel path needs check_vma off in
     shard_map), including a heterogeneous masked multi-subgroup stack.
     Runs in a subprocess because XLA_FLAGS must be set before jax
     initializes (excluded from -m fast; the full tier-1 suite covers it)."""
