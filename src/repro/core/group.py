@@ -42,13 +42,13 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-import time
 from typing import (Any, Callable, Deque, Dict, List, Mapping, Optional,
                     Protocol, Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import costmodel, delivery as delivery_mod
 from repro.core import desgraph as desgraph_mod
@@ -973,7 +973,9 @@ def _stream_program(n_subgroups: int, n_max: int, s_max: int,
     receive_fn = _kernel_receive(ring) if backend == "pallas" else None
     win_arr = np.asarray(windows, np.int32)
 
-    def fn(states, backlogs, ready, *masks):
+    # named so that its XLA module is ``jit_spindle_stream_round`` in a
+    # profiler trace, apart from the scan and batch programs
+    def spindle_stream_round(states, backlogs, ready, *masks):
         TRACE_EVENTS.append(((n_subgroups, n_max, s_max), windows,
                              backend))
         mm, sm = masks if masked else (None, None)
@@ -982,7 +984,7 @@ def _stream_program(n_subgroups: int, n_max: int, s_max: int,
             member_masks=mm, sender_masks=sm,
             receive_fn=receive_fn)
 
-    return jax.jit(fn)
+    return jax.jit(spindle_stream_round)
 
 
 # Programs that EMBED the stream round body inside a larger compiled
@@ -1088,12 +1090,11 @@ class GraphBackend:
     def run(self, cfg: GroupConfig, counts: Dict[int, np.ndarray]
             ) -> Tuple[RunReport, Dict[int, DeliveryLog]]:
         agg = _GraphAgg()
-        wall0 = time.perf_counter()
         if cfg.subgroups:
             program, args, rounds = self.program(cfg, counts)
             outs = [np.asarray(o) for o in program(*args)]
             self._finalize(cfg, counts, outs, rounds, agg)
-        return self._report(agg, wall0), agg.logs
+        return self._report(agg), agg.logs
 
     def run_batch(self, cfgs: List[GroupConfig],
                   counts_list: List[Dict[int, np.ndarray]]
@@ -1127,7 +1128,6 @@ class GraphBackend:
                         f"senders vs grid point 0's {len(s0.members)} / "
                         f"{len(s0.senders)}")
         b = len(cfgs)
-        wall0 = time.perf_counter()
         stacks = [self._stack(cfg, counts_list[i])
                   for i, cfg in enumerate(cfgs)]
         members, senders = stacks[0][0], stacks[0][1]
@@ -1153,9 +1153,7 @@ class GraphBackend:
             agg = _GraphAgg()
             self._finalize(cfgs[i], counts_list[i],
                            [o[i] for o in outs], stacks[i][3], agg)
-            # one wall clock covers the whole grid — stamp it under a
-            # batch key so nobody mistakes it for a per-point cost
-            report = self._report(agg, wall0, wall_key="batch_wall_s")
+            report = self._report(agg)
             report.extras["batch_devices"] = n_devices
             results.append((report, agg.logs))
         return results
@@ -1225,8 +1223,7 @@ class GraphBackend:
                    for node in spec.members):
                 agg.stalled = True
 
-    def _report(self, agg: _GraphAgg, wall0: float,
-                wall_key: str = "wall_s") -> RunReport:
+    def _report(self, agg: _GraphAgg) -> RunReport:
         per_node = [b / agg.duration / 1e3
                     for b in agg.per_node_bytes.values()
                     if agg.duration > 0 and b > 0]
@@ -1244,7 +1241,6 @@ class GraphBackend:
             rounds=agg.rounds,
             per_node_throughput=per_node,
             stalled=agg.stalled,
-            extras={wall_key: time.perf_counter() - wall0},
         )
 
     @staticmethod
@@ -1555,8 +1551,10 @@ class GroupStream:
         self._batches: List[np.ndarray] = []
         self._app_pub: List[np.ndarray] = []
         self._nulls: List[np.ndarray] = []
-        self._wall0 = time.perf_counter()
         self.rounds = 0
+        # device->host transfers of round results (step and view); the
+        # numpy mirror (des) makes none
+        self.host_syncs = 0
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -1657,7 +1655,20 @@ class GroupStream:
         """One protocol round: ``ready[g, s]`` app messages become ready
         at sender rank ``s`` of subgroup ``g`` (padded lanes must be 0).
         Window-throttled messages are carried in the backlog, exactly as
-        the scheduled scan does."""
+        the scheduled scan does.
+
+        In a ``jax.profiler`` trace a round is the host span
+        ``spindle.stream.step`` (metadata ``round``: the 0-based round
+        index), with two kinds of child spans:
+        ``spindle.stream.dispatch`` (launching the compiled round
+        program; on a ``des`` stream, the numpy round itself) and
+        ``spindle.stream.readback`` (bringing the round's results to the
+        host; the first one also waits for the program to finish).  The
+        step span's own time outside those is host bookkeeping."""
+        with TraceAnnotation("spindle.stream.step", round=self.rounds):
+            return self._step(ready)
+
+    def _step(self, ready) -> StreamView:
         if self.closed:
             raise RuntimeError(
                 "stream closed by a view change; continue on the stream "
@@ -1672,12 +1683,17 @@ class GroupStream:
                     f"subgroup {g} has {s_g} senders but ready names "
                     f"padded lanes {np.nonzero(ready[g, s_g:])[0] + s_g}")
             self._enqueued[g] += ready[g, :s_g].astype(np.int64)
-        (self._states, self._backlogs), (batch, pub, nulls) = \
-            self._program(self._states, self._backlogs,
-                          ready if self._numpy else jnp.asarray(ready),
-                          *self._mask_args)
-        pub, nulls = np.asarray(pub), np.asarray(nulls)
-        self._batches.append(np.asarray(batch))
+        with TraceAnnotation("spindle.stream.dispatch"):
+            (self._states, self._backlogs), (batch, pub, nulls) = \
+                self._program(self._states, self._backlogs,
+                              ready if self._numpy else jnp.asarray(ready),
+                              *self._mask_args)
+        with TraceAnnotation("spindle.stream.readback"):
+            pub, nulls = np.asarray(pub), np.asarray(nulls)
+            batch = np.asarray(batch)
+            if not self._numpy:
+                self.host_syncs += 3
+        self._batches.append(batch)
         self._app_pub.append(pub)
         self._nulls.append(nulls)
         self._app_cum += pub
@@ -1686,11 +1702,17 @@ class GroupStream:
         return dataclasses.replace(self.view(), app_pub=pub, nulls=nulls)
 
     def view(self) -> StreamView:
+        """The current watermarks, read back to the host (the span
+        ``spindle.stream.readback``)."""
+        with TraceAnnotation("spindle.stream.readback"):
+            delivered_num = np.asarray(self._states.delivered_num)
+            published = np.asarray(self._states.published)
+            backlog = np.asarray(self._backlogs)
+            if not self._numpy:
+                self.host_syncs += 3
         return StreamView(
-            round=self.rounds,
-            delivered_num=np.asarray(self._states.delivered_num),
-            published=np.asarray(self._states.published),
-            backlog=np.asarray(self._backlogs),
+            round=self.rounds, delivered_num=delivered_num,
+            published=published, backlog=backlog,
             n_members=self._n, n_senders=self._s)
 
     def app_publish_index(self, gid: int, rank: int,
@@ -1776,7 +1798,7 @@ class GroupStream:
         agg = self._aggregate()
         if self.rounds and np.asarray(self._backlogs).any():
             agg.stalled = True                # gave up with work queued
-        report = self.backend._report(agg, self._wall0)
+        report = self.backend._report(agg)
         report.extras["streamed_rounds"] = self.rounds
         self.group.delivery_logs = agg.logs
         self.group.last_report = report
@@ -1915,7 +1937,7 @@ class GroupStream:
                 agg.per_node_bytes[node] = \
                     agg.per_node_bytes.get(node, 0.0) + \
                     n_app * spec.msg_size
-        report = self.backend._report(agg, self._wall0)
+        report = self.backend._report(agg)
         report.extras["streamed_rounds"] = self.rounds
         report.extras["view_change"] = {
             "cut_seq": {g: int(c) for g, c in cut_seqs.items()},

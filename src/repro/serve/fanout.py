@@ -466,6 +466,7 @@ class ReplicatedEngine:
         req0 = sum(len(eng.completed) for eng in self.engines)
         steps0 = sum(eng.decode_steps for eng in self.engines)
         syncs0 = sum(eng.host_syncs for eng in self.engines)
+        streams = [bound.stream]           # every stream the loop steps
         round_no = 0
         while (round_no < max_rounds
                and (round_no < arrive_rounds
@@ -533,7 +534,9 @@ class ReplicatedEngine:
             if round_no in fail_at:
                 bound = self._fail_nodes(bound, fail_at[round_no],
                                          round_no, admission)
+                streams.append(bound.stream)
             round_no += 1
+        stream_syncs = sum(st.host_syncs for st in streams)
         # A scheduled failure the run never reached became moot (an
         # earlier cut / drain landed first): surface it rather than
         # raise — the chaos harness samples schedules without knowing
@@ -576,11 +579,11 @@ class ReplicatedEngine:
             "wall_s": wall,
             "fused": False,
             # device->host syncs taken INSIDE the round loop: one logits
-            # readback per engine decode + one watermark view per
-            # multicast round — the per-round hop count the fused path
-            # drives to zero
+            # readback per engine decode + the multicast streams' round
+            # readbacks — the per-round hop count the fused path drives
+            # to zero
             "host_hops": (sum(eng.host_syncs for eng in self.engines)
-                          - syncs0) + round_no,
+                          - syncs0) + stream_syncs,
         }
         if fused_fallback is not None:
             report.extras["serve"]["fused_fallback"] = fused_fallback

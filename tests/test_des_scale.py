@@ -40,7 +40,7 @@ def _eq(a, b, path=""):
     elif isinstance(a, dict):
         assert set(a) == set(b), path
         for k in a:
-            if k in ("wall_s", "backend"):
+            if k == "backend":
                 continue
             _eq(a[k], b[k], f"{path}.{k}")
     elif isinstance(a, (list, tuple)):
