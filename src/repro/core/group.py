@@ -1552,9 +1552,11 @@ class GroupStream:
         self._app_pub: List[np.ndarray] = []
         self._nulls: List[np.ndarray] = []
         self.rounds = 0
-        # device->host transfers of round results (step and view); the
-        # numpy mirror (des) makes none
+        # device->host transfers of round results (6 a step, 3 a view),
+        # and the blocking fetches that carry them (1 a step or view);
+        # the numpy mirror (des) makes none
         self.host_syncs = 0
+        self.host_waits = 0
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -1659,12 +1661,15 @@ class GroupStream:
 
         In a ``jax.profiler`` trace a round is the host span
         ``spindle.stream.step`` (metadata ``round``: the 0-based round
-        index), with two kinds of child spans:
-        ``spindle.stream.dispatch`` (launching the compiled round
-        program; on a ``des`` stream, the numpy round itself) and
-        ``spindle.stream.readback`` (bringing the round's results to the
-        host; the first one also waits for the program to finish).  The
-        step span's own time outside those is host bookkeeping."""
+        index) with two child spans: ``spindle.stream.dispatch``
+        (launching the compiled round program; on a ``des`` stream, the
+        numpy round itself) and one ``spindle.stream.readback`` (the
+        round's ``batch``/``app_pub``/``nulls`` and the three watermarks
+        of :meth:`view` brought to the host in one blocking fetch, which
+        also waits for the program to finish).  The step span's own time
+        outside those is host bookkeeping.  A step adds 6 to
+        ``host_syncs`` (arrays transferred) and 1 to ``host_waits``
+        (blocking fetches); a ``des`` stream adds to neither."""
         with TraceAnnotation("spindle.stream.step", round=self.rounds):
             return self._step(ready)
 
@@ -1688,32 +1693,46 @@ class GroupStream:
                 self._program(self._states, self._backlogs,
                               ready if self._numpy else jnp.asarray(ready),
                               *self._mask_args)
-        with TraceAnnotation("spindle.stream.readback"):
-            pub, nulls = np.asarray(pub), np.asarray(nulls)
-            batch = np.asarray(batch)
-            if not self._numpy:
-                self.host_syncs += 3
+        batch, pub, nulls, delivered_num, published, backlog = \
+            self._fetch(batch, pub, nulls, self._states.delivered_num,
+                        self._states.published, self._backlogs)
         self._batches.append(batch)
         self._app_pub.append(pub)
         self._nulls.append(nulls)
         self._app_cum += pub
         self._pub_cum += pub + nulls
         self.rounds += 1
-        return dataclasses.replace(self.view(), app_pub=pub, nulls=nulls)
+        return self._view(delivered_num, published, backlog,
+                          app_pub=pub, nulls=nulls)
 
     def view(self) -> StreamView:
-        """The current watermarks, read back to the host (the span
-        ``spindle.stream.readback``)."""
+        """The current watermarks (``delivered_num``, ``published``,
+        ``backlog``), read back to the host in one blocking fetch: one
+        ``spindle.stream.readback`` span, one ``host_waits``, three
+        ``host_syncs``."""
+        return self._view(*self._fetch(self._states.delivered_num,
+                                       self._states.published,
+                                       self._backlogs))
+
+    def _fetch(self, *arrays) -> Tuple[np.ndarray, ...]:
+        """``arrays`` on the host, in one ``spindle.stream.readback``
+        span.  ``jax.device_get`` starts every device->host copy before
+        it waits on any, so their latencies overlap and the host blocks
+        once; numpy arrays (the des mirror, a wrapped program's outputs)
+        pass through unchanged."""
         with TraceAnnotation("spindle.stream.readback"):
-            delivered_num = np.asarray(self._states.delivered_num)
-            published = np.asarray(self._states.published)
-            backlog = np.asarray(self._backlogs)
             if not self._numpy:
-                self.host_syncs += 3
+                self.host_syncs += len(arrays)
+                self.host_waits += 1
+            return jax.device_get(arrays)
+
+    def _view(self, delivered_num, published, backlog, app_pub=None,
+              nulls=None) -> StreamView:
         return StreamView(
             round=self.rounds, delivered_num=delivered_num,
             published=published, backlog=backlog,
-            n_members=self._n, n_senders=self._s)
+            n_members=self._n, n_senders=self._s, app_pub=app_pub,
+            nulls=nulls)
 
     def app_publish_index(self, gid: int, rank: int,
                           k: int) -> Optional[int]:
